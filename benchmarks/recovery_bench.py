@@ -120,7 +120,8 @@ def main17(out):
 
         t_host = timeit(host, warmup=1, iters=3)
         t_dev = timeit(device, warmup=1, iters=3)
-        roof = replay_roofline(state_bytes, payload_bytes, n)
+        roof = replay_roofline(state_bytes, payload_bytes, n,
+                               jax.devices()[0])
         if n == 64:
             speedup64 = t_host / t_dev
         out(row(f"exp17.n{n}.host_replay", t_host,

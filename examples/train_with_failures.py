@@ -34,7 +34,7 @@ def main():
     orig = T.get_config
     T.get_config = lambda name: cfg
     try:
-        losses, times = T.run(argv)
+        losses = T.run(argv).losses
     finally:
         T.get_config = orig
     assert losses[-1] < losses[0], "loss should decrease"

@@ -25,8 +25,7 @@ from typing import Dict, Optional
 from repro.analysis.segments import compose
 from repro.configs import INPUT_SHAPES, get_config
 from repro.distributed import sharding as shd
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh, peaks
 from repro.models.registry import build_model
 
 
@@ -42,9 +41,14 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * shape.global_batch      # decode: one token
 
 
+#: the chip the production-mesh analysis describes
+TARGET_KIND = "TPU v5 lite"
+
+
 def roofline(arch: str, shape_id: str, *, multi_pod: bool = False,
              rules: Optional[dict] = None) -> Dict:
     cfg = get_config(arch)
+    peak = peaks(TARGET_KIND)
     model = build_model(cfg)
     shape = INPUT_SHAPES[shape_id]
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -55,9 +59,9 @@ def roofline(arch: str, shape_id: str, *, multi_pod: bool = False,
         comp = compose(model, shape)
     t = comp["total"]
     terms = {
-        "compute_s": t["flops"] / PEAK_FLOPS,
-        "memory_s": t["bytes"] / HBM_BW,
-        "collective_s": t["coll_bytes"] / ICI_BW,
+        "compute_s": t["flops"] / peak.flops,
+        "memory_s": t["bytes"] / peak.hbm_bw,
+        "collective_s": t["coll_bytes"] / peak.ici_bw,
     }
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, shape) / chips
@@ -80,8 +84,8 @@ def roofline(arch: str, shape_id: str, *, multi_pod: bool = False,
 def measured_copy_bandwidth(nbytes: int = 1 << 26, iters: int = 5) -> float:
     """Measured memory-copy bandwidth of this host in bytes/s (2x the
     copied size: one read + one write stream). The replay roofline's
-    denominator on CPU backends, where the training state lives in host
-    RAM; on an accelerator backend use :data:`HBM_BW` instead."""
+    denominator on the CPU backend, where the training state lives in
+    host RAM."""
     import time as _time
 
     import numpy as np
@@ -96,15 +100,17 @@ def measured_copy_bandwidth(nbytes: int = 1 << 26, iters: int = 5) -> float:
 
 
 def replay_roofline(state_bytes: int, payload_bytes: int, n_diffs: int,
-                    bandwidth: Optional[float] = None) -> Dict:
+                    device) -> Dict:
     """Memory-bandwidth lower bound for replaying ``n_diffs``
     differentials through a stateful optimizer: each step must read and
     write the full optimizer state (params + both f32 moments) once and
     read its compressed payload — nothing less recovers Adam exactly.
-    ``payload_bytes`` is per differential."""
-    bw = bandwidth if bandwidth else (
-        HBM_BW if os.environ.get("REPRO_ACCEL") else
-        measured_copy_bandwidth())
+    ``payload_bytes`` is per differential. ``device`` is the
+    ``jax.Device`` holding the state: the bound uses the host's
+    measured copy rate on the CPU backend and the chip's published HBM
+    rate otherwise (an unknown chip raises)."""
+    bw = (measured_copy_bandwidth() if device.platform == "cpu"
+          else peaks(device.device_kind).hbm_bw)
     traffic = n_diffs * (2 * state_bytes + payload_bytes)
     return {"traffic_bytes": int(traffic), "bandwidth": float(bw),
             "min_seconds": traffic / bw}

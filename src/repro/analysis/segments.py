@@ -336,12 +336,8 @@ def segments_for(model, shape: ShapeConfig) -> List[Segment]:
 # --------------------------------------------------------------------------
 
 def normalize_cost_analysis(ca) -> Dict[str, float]:
-    """jax >= 0.5 returns one dict; jax <= 0.4.x one dict per device."""
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict ({} when unavailable)."""
+    return ca or {}
 
 
 def measure_segment(seg: Segment) -> Dict[str, float]:

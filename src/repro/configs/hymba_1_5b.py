@@ -3,7 +3,7 @@
 32L d_model=1600 25H (GQA kv=5) d_ff=5504 vocab=32001, ssm_state=16.
 Parallel attention + mamba heads in every block; sliding-window attention
 everywhere except the first / middle / last layers (full attention).
-Meta tokens from the paper are omitted (noted in DESIGN.md).
+Meta tokens from the paper are omitted.
 """
 from repro.configs.base import ArchConfig, SSMConfig
 

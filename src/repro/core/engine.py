@@ -71,8 +71,8 @@ FLAG_MAP: Dict[str, tuple] = {
 }
 
 #: parser dests that are runtime inputs, not engine/store config
-RUNTIME_FLAGS = frozenset({"arch", "reduced", "steps", "batch", "seq",
-                           "seed", "log_every", "fail_at", "clean",
+RUNTIME_FLAGS = frozenset({"arch", "reduced", "layers", "steps", "batch",
+                           "seq", "seed", "log_every", "fail_at", "clean",
                            "log_level"})
 
 

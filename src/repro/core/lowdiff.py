@@ -235,11 +235,11 @@ class LowDiff:
 
         Never hangs: a handler exception on the consumer thread is
         re-raised here as :class:`~repro.core.reusing_queue.
-        CheckpointingError`, the wait is bounded by ``timeout`` (default
-        ``flush_timeout``), and the store-level flush — including the
-        maintenance drain — shares the same deadline budget."""
+        CheckpointingError`, the queue wait raises once ``timeout``
+        (default ``flush_timeout``) passes with no differential handled,
+        and the store-level flush — including the maintenance drain —
+        is bounded by ``timeout`` as well."""
         t = timeout if timeout is not None else self.flush_timeout
-        deadline = time.monotonic() + t
         t0 = time.perf_counter()
         with trace_span("ckpt.flush", "persist"):
             wait_drained(self.queue, lambda: self._processed,
@@ -248,7 +248,7 @@ class LowDiff:
             for f in self._pending:
                 f.result()
             self._pending.clear()
-            self.store.flush(timeout=max(0.0, deadline - time.monotonic()))
+            self.store.flush(timeout=t)
         TIMELINE.event("flush_stall", time.perf_counter() - t0,
                        step=self._step_counter)
 

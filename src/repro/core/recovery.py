@@ -197,20 +197,22 @@ def _parallel_replay(params, mu0, nu0, stacked, count0, lr, *,
     return p2, mu2, nu2
 
 
-def _decode_prefix(diffs: List[Tuple[int, Any]]):
-    """Host-decode payloads in order, stopping at the first failure.
+def _decode_prefix(diffs: List[Tuple[int, Any]], p_leaves):
+    """Host-decode payloads in order, stopping at the first corrupt one.
     Returns (dense grads for the longest decodable prefix, error or
     None) — ``contiguous_prefix`` semantics for *payload* corruption:
-    a bad differential at position k cuts the chain at k instead of
-    raising mid-replay and losing the whole recovery."""
-    gs, err = [], None
+    a differential at position k that fails :func:`_check_payload`
+    cuts the chain at k instead of raising mid-replay and losing the
+    whole recovery. A failure of the decode itself (a kernel that does
+    not compile or run) is not corruption and propagates."""
+    gs = []
     for _, payload in diffs:
         try:
-            gs.append(maybe_decompress(payload))
-        except Exception as e:          # decode failure, any backend
-            err = e
-            break
-    return gs, err
+            _check_payload(payload, p_leaves)
+        except CorruptDifferential as e:
+            return gs, e
+        gs.append(maybe_decompress(payload))
+    return gs, None
 
 
 def replay_parallel(params, opt: AdamState, diffs: List[Tuple[int, Any]], *,
@@ -230,10 +232,11 @@ def replay_parallel(params, opt: AdamState, diffs: List[Tuple[int, Any]], *,
     reassociation the unwindowed scan already accepts. ``None`` (or 0)
     replays everything in one window.
 
-    Each window is host-decoded *before* its scan launches; a payload
-    that fails to decode cuts the chain there — the state replayed so
-    far is returned rather than thrown away. Returns
-    ``(params, opt, applied)`` with ``applied`` the number of
+    Each window is host-decoded *before* its scan launches; a corrupt
+    payload cuts the chain there — the state replayed so far is
+    returned rather than thrown away — while a failing decode kernel
+    propagates. Returns ``(params, opt, applied)`` with ``applied`` the
+    number of
     differentials actually replayed (== ``len(diffs)`` when the whole
     chain was clean)."""
     from repro.checkpoint.io import COPY_METER
@@ -245,7 +248,7 @@ def replay_parallel(params, opt: AdamState, diffs: List[Tuple[int, Any]], *,
     mu, nu, count = opt.mu, opt.nu, opt.count
     applied = 0
     for i in range(0, len(diffs), w):
-        gs, err = _decode_prefix(diffs[i:i + w])
+        gs, err = _decode_prefix(diffs[i:i + w], jax.tree.leaves(params))
         if gs:
             stacked = jax.tree.map(lambda *xs: jnp.stack(
                 [x.astype(jnp.float32) for x in xs]), *gs)
@@ -288,34 +291,53 @@ def _device_replay(p_leaves, mu_leaves, nu_leaves, g_stacks, count0, lr, *,
     return p2, mu2, nu2, c2
 
 
-def _check_wire(payload) -> None:
-    """Cheap consistency check of a payload's wire containers: the
-    block-row count must match the dense shape the container claims to
-    decode to — the device path never materializes the dense form, so a
-    truncated/corrupt container would otherwise surface as a shape
-    error deep inside the jitted scan instead of a clean chain cut."""
+class CorruptDifferential(ValueError):
+    """A stored differential whose wire containers do not describe the
+    tensors they claim to — a torn or truncated write. Replay cuts the
+    chain at such a differential; every other error propagates."""
+
+
+def _check_payload(payload, p_leaves) -> None:
+    """Cheap consistency check of a stored differential before any
+    decode: one container (or dense array) per model leaf, each
+    decoding to that leaf's shape, with a block-row count that matches
+    the shape. The device path never materializes the dense form, so a
+    truncated container would otherwise surface as a shape error deep
+    inside the jitted scan instead of a clean chain cut. Raises
+    :class:`CorruptDifferential`."""
     import numpy as np
-    for leaf in jax.tree.leaves(payload, is_leaf=_is_compressed):
-        if not _is_compressed(leaf):
+    g_leaves = jax.tree.leaves(payload, is_leaf=_is_compressed)
+    if len(g_leaves) != len(p_leaves):
+        raise CorruptDifferential(
+            f"differential has {len(g_leaves)} leaves, model has "
+            f"{len(p_leaves)}")
+    for g, p in zip(g_leaves, p_leaves):
+        shape = tuple(g.shape if _is_compressed(g) else jnp.shape(g))
+        if shape != tuple(p.shape):
+            raise CorruptDifferential(
+                f"differential leaf of shape {shape} for a model leaf of "
+                f"shape {tuple(p.shape)}")
+        if not _is_compressed(g):
             continue
-        n = int(np.prod(leaf.shape)) if leaf.shape else 1
-        nb = -(-n // leaf.block)            # ceil div
-        lead = getattr(leaf, "values", None)
-        lead = leaf.q if lead is None else lead
+        n = int(np.prod(shape)) if shape else 1
+        nb = -(-n // g.block)               # ceil div
+        lead = getattr(g, "values", None)
+        lead = g.q if lead is None else lead
         if lead.shape[0] != nb:
-            raise ValueError(
+            raise CorruptDifferential(
                 f"corrupt differential: {lead.shape[0]} block rows for "
-                f"shape {leaf.shape} (expected {nb})")
+                f"shape {shape} (expected {nb})")
 
 
-def _stage_window(diffs: List[Tuple[int, Any]]):
+def _stage_window(diffs: List[Tuple[int, Any]], p_leaves):
     """H2D-stage a window's payloads in wire form. Uploads each
     differential's compressed buffers to the device (async
     ``device_put`` under the hood — the transfer overlaps whatever scan
     is already running) and stacks them along a leading axis for
-    ``lax.scan``. A payload that fails to stage cuts the window there
-    (``contiguous_prefix`` semantics). Returns
-    ``(stacked | None, n_staged, error | None)``."""
+    ``lax.scan``. A corrupt payload — torn containers, leaves that do
+    not match the model, or a structure change mid-window — cuts the
+    window there (``contiguous_prefix`` semantics); a failing upload
+    propagates. Returns ``(stacked | None, n_staged, error | None)``."""
     from repro.checkpoint.io import COPY_METER
     from repro.obs.trace import trace_span
     with trace_span("replay.h2d", "recovery", n=len(diffs)) as sp:
@@ -323,19 +345,19 @@ def _stage_window(diffs: List[Tuple[int, Any]]):
         nbytes = 0
         for _, payload in diffs:
             try:
-                _check_wire(payload)
-                dev = jax.tree.map(jnp.asarray, payload)
-                tdef = jax.tree.structure(dev)
+                _check_payload(payload, p_leaves)
+                tdef = jax.tree.structure(payload)
                 if template is None:
                     template = tdef
                 elif tdef != template:
-                    raise ValueError("differential structure changed "
-                                     "mid-window")
-                nbytes += sum(l.nbytes for l in jax.tree.leaves(dev))
-                staged.append(dev)
-            except Exception as e:
+                    raise CorruptDifferential(
+                        "differential structure changed mid-window")
+            except CorruptDifferential as e:
                 err = e
                 break
+            dev = jax.tree.map(jnp.asarray, payload)
+            nbytes += sum(l.nbytes for l in jax.tree.leaves(dev))
+            staged.append(dev)
         if not staged:
             return None, 0, err
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *staged)
@@ -356,8 +378,10 @@ def replay_device(params, opt: AdamState, diffs: List[Tuple[int, Any]], *,
 
     Windows are double-buffered: window N's scan is dispatched
     asynchronously, then window N+1's payloads stage H2D while it runs.
-    A payload that fails to decode/stage cuts the chain at that diff.
-    Returns ``(params, opt, applied)``."""
+    A corrupt payload (:class:`CorruptDifferential`, found before
+    dispatch) cuts the chain at that diff; a compile or runtime error
+    of the replay program propagates. Returns ``(params, opt,
+    applied)``."""
     if not diffs:
         return params, opt, 0
     if window is not None and window < 0:
@@ -370,24 +394,21 @@ def replay_device(params, opt: AdamState, diffs: List[Tuple[int, Any]], *,
     count = jnp.asarray(opt.count, jnp.int32)
     applied = 0
     windows = [diffs[i:i + w] for i in range(0, len(diffs), w)]
-    nxt = _stage_window(windows[0])
+    nxt = _stage_window(windows[0], p_leaves)
     for i in range(len(windows)):
         stacked, n, err = nxt
         if n:
             g_stacks = jax.tree.leaves(stacked, is_leaf=_is_compressed)
-            try:
-                p_leaves, mu_l, nu_l, count = _device_replay(
-                    p_leaves, mu_l, nu_l, g_stacks, count,
-                    jnp.float32(lr), b1=b1, b2=b2, eps=eps, use_pallas=up)
-                applied += n
-            except Exception as e:      # structure/shape mismatch
-                err = e
+            p_leaves, mu_l, nu_l, count = _device_replay(
+                p_leaves, mu_l, nu_l, g_stacks, count,
+                jnp.float32(lr), b1=b1, b2=b2, eps=eps, use_pallas=up)
+            applied += n
         if err is not None:
             break
         if i + 1 < len(windows):
             # double buffer: the scan above was dispatched async; the
             # next window's (compressed, hence small) H2D runs under it
-            nxt = _stage_window(windows[i + 1])
+            nxt = _stage_window(windows[i + 1], p_leaves)
     return (jax.tree.unflatten(treedef, p_leaves),
             AdamState(jax.tree.unflatten(treedef, mu_l),
                       jax.tree.unflatten(treedef, nu_l), count),

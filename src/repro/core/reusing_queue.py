@@ -146,11 +146,14 @@ def wait_drained(q: ReusingQueue, processed: Callable[[], int],
 
     Raises :class:`CheckpointingError` (chaining the handler exception)
     if the consumer died, and :class:`TimeoutError` when ``timeout``
-    elapses — a flush must never hang forever on a counter the dead
-    consumer can no longer advance.
+    passes with no item handled — a flush must never hang forever on a
+    counter a wedged consumer no longer advances, while a consumer that
+    is slow but advancing (a host-replica step over a published-width
+    model takes tens of seconds) is not wedged.
     """
     deadline = time.monotonic() + timeout
-    while processed() < q.enqueued:
+    seen = processed()
+    while seen < q.enqueued:
         if q.error is not None:
             raise CheckpointingError(
                 "checkpointing consumer failed; differentials after step "
@@ -161,9 +164,12 @@ def wait_drained(q: ReusingQueue, processed: Callable[[], int],
                 f"{q.enqueued - processed()} differential(s) remain queued")
         if time.monotonic() > deadline:
             raise TimeoutError(
-                f"flush did not drain within {timeout:.1f}s "
-                f"({processed()}/{q.enqueued} handled)")
+                f"flush handled nothing for {timeout:.1f}s "
+                f"({seen}/{q.enqueued} handled)")
         time.sleep(poll_s)
+        now = processed()
+        if now != seen:
+            seen, deadline = now, time.monotonic() + timeout
     if q.error is not None:
         raise CheckpointingError(
             "checkpointing consumer failed") from q.error

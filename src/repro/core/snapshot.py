@@ -33,15 +33,25 @@ import numpy as np
 from repro.checkpoint.io import COPY_METER
 
 
+def _issue_d2h(leaf) -> None:
+    """Enqueue one leaf's D2H transfer. Host leaves need none; a jax
+    array that this process cannot address whole (a shard living on
+    another host) raises — a snapshot that skipped it would persist a
+    checkpoint with a hole."""
+    if not isinstance(leaf, jax.Array):
+        return
+    if not leaf.is_fully_addressable:
+        raise ValueError(
+            f"cannot snapshot a {leaf.shape} array that is not fully "
+            f"addressable from this process (sharding {leaf.sharding})")
+    leaf.copy_to_host_async()
+
+
 def start_host_transfer(tree) -> Any:
     """Phase 1: enqueue non-blocking D2H transfers for every jax leaf.
     Returns the tree unchanged (transfers run in the background)."""
     for leaf in jax.tree.leaves(tree):
-        if isinstance(leaf, jax.Array):
-            try:
-                leaf.copy_to_host_async()
-            except AttributeError:  # non-addressable / already on host
-                pass
+        _issue_d2h(leaf)
     return tree
 
 
@@ -154,12 +164,7 @@ class ShardedPendingSnapshot:
         self._issued_at = time.perf_counter()
         for group in self._groups:      # chunked issue, shard order
             for i in group:
-                leaf = self._leaves[i]
-                if isinstance(leaf, jax.Array):
-                    try:
-                        leaf.copy_to_host_async()
-                    except AttributeError:
-                        pass
+                _issue_d2h(self._leaves[i])
 
     @property
     def shards(self) -> int:

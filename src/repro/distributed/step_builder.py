@@ -11,7 +11,7 @@ gradient would gather it). ``compress_sharded`` wraps the block top-k in
 a shard_map so each device compresses — and later checkpoints — exactly
 its own gradient slice. The differential checkpoint is therefore sharded
 the same way as the optimizer state, and recovery is shard-local too
-(beyond-paper extension; see DESIGN.md §3).
+(an extension beyond the paper).
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.compression.sparse import SparseGrad, k_for, topk_compress
 from repro.core.steps import make_train_step
@@ -121,8 +120,8 @@ def compress_sharded(grads, pspecs, mesh, rho: float):
             sg = topk_compress(x, rho)
             return sg.values, sg.indices
 
-        fn = shard_map(local, mesh=mesh, in_specs=(sp,),
-                       out_specs=out_spec(sp), check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=(sp,),
+                           out_specs=out_spec(sp), check_vma=False)
         vals, idx = fn(g)
         # NOTE: block order follows the shard layout (each device's local
         # flatten); the differential checkpoint is saved and replayed

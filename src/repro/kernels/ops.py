@@ -1,10 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced jnp on the host, which validates the exact
-TPU program. On a TPU backend the same call sites compile the Mosaic
-kernels. ``use_pallas=False`` routes to the pure-jnp oracle instead
-(used to cross-check and as the default inside larger jitted graphs).
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs as traced jnp on the host, which validates the kernel
+program. On a TPU backend the same call sites compile the Mosaic
+kernels; any other backend raises. ``use_pallas=False`` routes to the
+pure-jnp oracle instead (used to cross-check and as the default inside
+larger jitted graphs).
 """
 from __future__ import annotations
 
@@ -26,7 +27,14 @@ from repro.kernels import topk as _tk
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels on the CPU, compile them for the TPU, and
+    refuse any other backend rather than quietly interpreting there."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on 'tpu' (compiled) or 'cpu' "
+            f"(interpreted), not on {backend!r}")
+    return backend == "cpu"
 
 
 def _to_blocks(x: jax.Array, block: int):

@@ -25,34 +25,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.topk import scatter_topk, select_topk
+
 ROWS = 8          # rows (blocks) per grid step — one f32 sublane tile
 
 
 def _pack_kernel(x_ref, q_ref, idx_ref, scale_ref, *, k: int, block: int):
-    x = x_ref[...]                                     # (R, BLOCK)
-    xf = x.astype(jnp.float32)
-    mag = jnp.abs(xf)
-    iota = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 1)
-
-    def body(i, carry):
-        mag, vals, idxs = carry
-        m = jnp.max(mag, axis=1, keepdims=True)        # (R, 1)
-        hit = mag == m
-        idx = jnp.min(jnp.where(hit, iota, block), axis=1)      # (R,)
-        sel = iota == idx[:, None]
-        val = jnp.sum(jnp.where(sel, xf, 0.0), axis=1)          # (R,)
-        vals = jax.lax.dynamic_update_index_in_dim(vals, val, i, 1)
-        idxs = jax.lax.dynamic_update_index_in_dim(idxs, idx, i, 1)
-        mag = jnp.where(sel, -1.0, mag)
-        return mag, vals, idxs
-
-    vals0 = jnp.zeros((x.shape[0], k), jnp.float32)
-    idxs0 = jnp.zeros((x.shape[0], k), jnp.int32)
-    _, vals, idxs = jax.lax.fori_loop(0, k, body, (mag, vals0, idxs0))
-    # the first selection is the absmax of the block — its magnitude is
-    # the quantization range, no extra reduction over the tile needed
+    vals, idxs = select_topk(x_ref[...].astype(jnp.float32), k, block)
+    # the first selection is the absmax of the block, so the max over
+    # the k picks is the quantization range — no extra reduction over
+    # the (R, BLOCK) tile is needed
     scale = jnp.maximum(
-        jnp.abs(jax.lax.dynamic_index_in_dim(vals, 0, 1)) / 127.0, 1e-12)
+        jnp.max(jnp.abs(vals), axis=1, keepdims=True) / 127.0, 1e-12)
     q = jnp.clip(jnp.round(vals / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     idx_ref[...] = idxs
@@ -80,24 +64,26 @@ def pack_select(xb: jax.Array, k: int, *, interpret: bool = False):
     )(xb)
 
 
-def _span_pack_kernel(x_ref, q_ref, scale_ref, *, bits: int):
+def _span_pack_kernel(*refs, bits: int):
     """Row-blocked absmax quantizer for state-row spans: one scale per
     row, int8 values or two int4 nibbles per byte (two's complement,
-    even/odd columns -> low/high nibble)."""
-    x = x_ref[...].astype(jnp.float32)                  # (R, C)
+    even/odd columns -> low/high nibble). For int4 the even and odd
+    columns arrive as two inputs, split by the wrapper: Mosaic has no
+    lane-strided slice."""
+    *xs, q_ref, scale_ref = refs
+    xs = [r[...].astype(jnp.float32) for r in xs]      # (R, C) or 2x (R, C/2)
     qmax = 127.0 if bits == 8 else 7.0
+    absmax = functools.reduce(
+        jnp.maximum, [jnp.max(jnp.abs(x), axis=1, keepdims=True) for x in xs])
     # reciprocal-multiply (not /qmax): matches the numpy host codec bit
     # for bit regardless of XLA's divide-by-constant rewrite
-    scale = jnp.maximum(
-        jnp.max(jnp.abs(x), axis=1, keepdims=True)
-        * jnp.float32(1.0 / qmax), 1e-12)
-    qi = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
+    scale = jnp.maximum(absmax * jnp.float32(1.0 / qmax), 1e-12)
+    qs = [jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
+          for x in xs]
     if bits == 8:
-        q_ref[...] = qi.astype(jnp.int8)
+        q_ref[...] = qs[0].astype(jnp.int8)
     else:
-        R, C = qi.shape                                 # C even (pre-padded)
-        lo = jax.lax.slice(qi, (0, 0), (R, C - 1), (1, 2)) & 0xF
-        hi = jax.lax.slice(qi, (0, 1), (R, C), (1, 2)) & 0xF
+        lo, hi = (q & 0xF for q in qs)
         q_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
     scale_ref[...] = scale
 
@@ -106,7 +92,8 @@ def span_pack(xb: jax.Array, *, bits: int, interpret: bool = False):
     """xb: (nb, cols) f32 rows (cols even when bits == 4) ->
     (q (nb, wire_cols), scale f32 (nb, 1)) where wire_cols is cols for
     int8 and cols // 2 for nibble-packed int4 — the fused row-span
-    quantizer feeding :class:`~repro.compression.quant_span.QuantSpan`."""
+    quantizer feeding :class:`~repro.compression.quant_span.QuantSpan`.
+    One grid step holds whole rows, so a row must fit in VMEM."""
     assert bits in (8, 4)
     nb, cols = xb.shape
     assert bits == 8 or cols % 2 == 0
@@ -114,32 +101,24 @@ def span_pack(xb: jax.Array, *, bits: int, interpret: bool = False):
     assert nb % rows == 0
     wire_cols = cols if bits == 8 else cols // 2
     wire_dt = jnp.int8 if bits == 8 else jnp.uint8
+    xs = [xb] if bits == 8 else [xb[:, 0::2], xb[:, 1::2]]
     kernel = functools.partial(_span_pack_kernel, bits=bits)
     return pl.pallas_call(
         kernel,
         grid=(nb // rows,),
-        in_specs=[pl.BlockSpec((rows, cols), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((rows, x.shape[1]), lambda i: (i, 0))
+                  for x in xs],
         out_specs=[pl.BlockSpec((rows, wire_cols), lambda i: (i, 0)),
                    pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((nb, wire_cols), wire_dt),
                    jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
         interpret=interpret,
-    )(xb)
+    )(*xs)
 
 
 def _unpack_kernel(q_ref, idx_ref, scale_ref, out_ref, *, block: int):
     vals = q_ref[...].astype(jnp.float32) * scale_ref[...]      # (R, k)
-    idxs = idx_ref[...]
-    R, k = vals.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (R, block), 1)
-
-    def body(i, acc):
-        sel = iota == jax.lax.dynamic_index_in_dim(idxs, i, 1)  # (R,1)->bcast
-        v = jax.lax.dynamic_index_in_dim(vals, i, 1)
-        return acc + jnp.where(sel, v, 0.0)
-
-    acc = jax.lax.fori_loop(0, k, body, jnp.zeros((R, block), jnp.float32))
-    out_ref[...] = acc
+    out_ref[...] = scatter_topk(vals, idx_ref[...], block)
 
 
 def pack_scatter(q: jax.Array, idxs: jax.Array, scale: jax.Array,

@@ -30,7 +30,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.topk import scatter_topk
+
 ROWS = 8          # rows (blocks) per grid step — one f32 sublane tile
+SPAN_COLS = 8192  # wire lanes per quantized row-span tile
 
 
 def _adam_epilogue(hyper_ref, g, p_ref, mu_ref, nu_ref,
@@ -51,24 +54,9 @@ def _adam_epilogue(hyper_ref, g, p_ref, mu_ref, nu_ref,
     nu_out[...] = nu
 
 
-def _scatter(vals, idxs, block: int):
-    """(R, k) values + block-local indices -> dense (R, block) f32.
-    Indices within a block are distinct by construction (iterative
-    argmax / top_k), so add-scatter == write-scatter."""
-    R, k = vals.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (R, block), 1)
-
-    def body(i, acc):
-        sel = iota == jax.lax.dynamic_index_in_dim(idxs, i, 1)
-        v = jax.lax.dynamic_index_in_dim(vals, i, 1)
-        return acc + jnp.where(sel, v.astype(jnp.float32), 0.0)
-
-    return jax.lax.fori_loop(0, k, body, jnp.zeros((R, block), jnp.float32))
-
-
 def _topk_apply_kernel(hyper_ref, vals_ref, idx_ref, p_ref, mu_ref, nu_ref,
                        p_out, mu_out, nu_out, *, block: int):
-    g = _scatter(vals_ref[...], idx_ref[...], block)
+    g = scatter_topk(vals_ref[...], idx_ref[...], block)
     _adam_epilogue(hyper_ref, g, p_ref, mu_ref, nu_ref,
                    p_out, mu_out, nu_out)
 
@@ -77,7 +65,7 @@ def _packed_apply_kernel(hyper_ref, q_ref, idx_ref, scale_ref,
                          p_ref, mu_ref, nu_ref,
                          p_out, mu_out, nu_out, *, block: int):
     vals = q_ref[...].astype(jnp.float32) * scale_ref[...]      # (R, k)
-    g = _scatter(vals, idx_ref[...], block)
+    g = scatter_topk(vals, idx_ref[...], block)
     _adam_epilogue(hyper_ref, g, p_ref, mu_ref, nu_ref,
                    p_out, mu_out, nu_out)
 
@@ -162,24 +150,29 @@ def quant_apply(q, scale, p, mu, nu, hyper, *, interpret: bool = False):
 
 # -------------------- quantized row-span recovery --------------------
 
-def _quant_span_kernel(q_ref, scale_ref, out_ref, *, bits: int):
-    """Dequantize a quantized row-span wire tile: int8 values or
-    nibble-packed int4 (low nibble = even column, two's complement) ->
-    dense f32 rows, scaled by the per-row absmax scale."""
-    q = q_ref[...]
+def _quant_span_kernel(q_ref, scale_ref, *out_refs, bits: int):
+    """Dequantize a quantized row-span wire tile, scaled by the per-row
+    absmax scale: int8 values -> one f32 tile; nibble-packed int4 (low
+    nibble = even column, two's complement) -> the even-column and the
+    odd-column f32 tiles, which the wrapper interleaves (an in-kernel
+    lane interleave does not fit VMEM at real row widths)."""
+    scale = scale_ref[...]
     if bits == 8:
-        g = q.astype(jnp.float32)
-    else:
-        u = q.astype(jnp.int32)
-        lo = u & 0xF
-        hi = (u >> 4) & 0xF
-        lo = jnp.where(lo > 7, lo - 16, lo)
-        hi = jnp.where(hi > 7, hi - 16, hi)
-        R, W = u.shape
-        even = jax.lax.broadcasted_iota(jnp.int32, (R, 2 * W), 1) % 2 == 0
-        g = jnp.where(even, jnp.repeat(lo, 2, axis=1),
-                      jnp.repeat(hi, 2, axis=1)).astype(jnp.float32)
-    out_ref[...] = g * scale_ref[...]
+        out_refs[0][...] = q_ref[...].astype(jnp.float32) * scale
+        return
+    u = q_ref[...].astype(jnp.int32)
+    for out, nib in zip(out_refs, (u & 0xF, (u >> 4) & 0xF)):
+        out[...] = jnp.where(nib > 7, nib - 16, nib).astype(
+            jnp.float32) * scale
+
+
+def _span_tiling(wc: int):
+    """(padded wire columns, lane tile): one full-width tile for narrow
+    rows, else SPAN_COLS-lane tiles — a stacked-layer leaf's row is a
+    whole layer's matrix (millions of columns), far beyond VMEM."""
+    if wc <= SPAN_COLS:
+        return wc, wc
+    return wc + (-wc % SPAN_COLS), SPAN_COLS
 
 
 def quant_span_decode(q, scale, *, bits: int, interpret: bool = False):
@@ -189,17 +182,23 @@ def quant_span_decode(q, scale, *, bits: int, interpret: bool = False):
     nb, wc = q.shape
     rows = min(ROWS, nb)
     assert nb % rows == 0
-    cols = wc if bits == 8 else 2 * wc
+    wpad, tw = _span_tiling(wc)
+    if wpad != wc:
+        q = jnp.pad(q, ((0, 0), (0, wpad - wc)))
+    tile = pl.BlockSpec((rows, tw), lambda i, j: (i, j))
+    n_out = 1 if bits == 8 else 2
     kernel = functools.partial(_quant_span_kernel, bits=bits)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
-        grid=(nb // rows,),
-        in_specs=[pl.BlockSpec((rows, wc), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, cols), jnp.float32),
+        grid=(nb // rows, wpad // tw),
+        in_specs=[tile, pl.BlockSpec((rows, 1), lambda i, j: (i, 0))],
+        out_specs=[tile] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((nb, wpad), jnp.float32)] * n_out,
         interpret=interpret,
     )(q, scale)
+    if bits == 8:
+        return outs[0][:, :wc]
+    return jnp.stack(outs, axis=2).reshape(nb, 2 * wpad)[:, :2 * wc]
 
 
 def quant_span_apply(q, scale, dst, start, *, bits: int,

@@ -10,8 +10,13 @@ stack) — ``EngineConfig.from_args`` owns the flag→config translation
 in one place, and ``tests/test_flag_config_sync.py`` fails if a flag
 and its config field drift apart.
 
+``--reduced`` is the CPU cut used by the tests; on a chip the model
+keeps its published widths and ``--layers N`` cuts only its depth.
+
 Examples::
 
+    PYTHONPATH=src python -m repro.launch.train --arch gpt2-l --layers 8 \
+        --batch 4 --seq 1024 --steps 20 --strategy lowdiff
     PYTHONPATH=src python -m repro.launch.train --arch gpt2-l --reduced \
         --steps 50 --strategy lowdiff --ckpt-dir /tmp/ck
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \
@@ -22,9 +27,11 @@ Examples::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import shutil
 import time
 import warnings
+from typing import Dict, List
 
 import jax
 import numpy as np
@@ -34,6 +41,7 @@ from repro.configs import get_config
 from repro.core.engine import STRATEGIES, EngineConfig, make_engine
 from repro.core.steps import init_state, make_train_step
 from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.obs.log import configure as configure_logging, get_logger
 from repro.obs.metrics import REGISTRY
@@ -72,12 +80,34 @@ def _stall_suffix(rec) -> str:
     return " ".join(parts)
 
 
-def run(args):
-    configure_logging(getattr(args, "log_level", "info"))
-    log = get_logger("train")
+@dataclasses.dataclass
+class RunResult:
+    """What one training run produced."""
+    losses: List[float]
+    times: List[float]                 #: wall seconds per step
+    #: one record per injected failure: the step it struck, the step
+    #: training resumed from and, for LowDiff, the differentials replayed
+    recoveries: List[Dict[str, int]]
+
+
+def arch_config(args):
+    """The model config the flags ask for: ``--arch``, cut to CPU size
+    by ``--reduced`` and to ``--layers`` layers (every width kept)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    layers = getattr(args, "layers", 0)
+    if layers:
+        get_logger("train").info(
+            f"depth cut: n_layers {cfg.n_layers} -> {layers}")
+        cfg = cfg.replace(n_layers=layers)
+    return cfg
+
+
+def run(args) -> RunResult:
+    configure_logging(getattr(args, "log_level", "info"))
+    log = get_logger("train")
+    cfg = arch_config(args)
     model = build_model(cfg)
     log.info(f"arch={cfg.name} params={model.n_params() / 1e6:.1f}M "
              f"strategy={args.strategy}")
@@ -95,7 +125,7 @@ def run(args):
     plain_step = make_train_step(model, mode=mode, lr=args.lr, rho=args.rho)
     stream = TokenStream(cfg, args.seq, args.batch, seed=args.seed)
 
-    losses, times = [], []
+    losses, times, recoveries = [], [], []
     t_start = time.perf_counter()
     for t in range(args.steps):
         batch = next(stream)
@@ -118,12 +148,16 @@ def run(args):
             log.info(f"\n*** injected failure at step {t + 1} ***")
             assert strat is not None, "--fail-at needs a strategy"
             strat.flush()
+            record = {"fail_at": t + 1}
             if args.strategy == "lowdiff_plus":
                 state = strat.recover_software(state)
             else:
-                state, n = strat.recover()
-            log.info(f"recovered at step {int(state['step'])}; resuming\n")
-            stream.step = int(state["step"])
+                state = None     # a killed process keeps no device state
+                state, record["applied"] = strat.recover()
+            record["step"] = int(state["step"])
+            recoveries.append(record)
+            log.info(f"recovered at step {record['step']}; resuming\n")
+            stream.step = record["step"]
 
     wall = time.perf_counter() - t_start
     if strat is not None:
@@ -143,7 +177,7 @@ def run(args):
         extras = [{"kind": "metric", **m} for m in REGISTRY.collect()]
         n = TIMELINE.write_jsonl(engine_cfg.metrics_out, extra=extras)
         log.info(f"wrote {n} records -> {engine_cfg.metrics_out}")
-    return losses, times
+    return RunResult(losses, times, recoveries)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -151,6 +185,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="gpt2-l")
     ap.add_argument("--reduced", action="store_true",
                     help="reduced config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the published config's depth to this many "
+                         "layers, keeping every width (0 = published "
+                         "depth)")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
@@ -300,6 +338,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main():
+    enable_compile_cache()
     run(build_parser().parse_args())
 
 

@@ -149,7 +149,6 @@ def _moe_apply_ep(p, x, cfg: ArchConfig, ctx, ep: int):
 
     Capacity is per-(data shard) — the t5x/Switch 'group' capacity
     semantics; with one shard it equals the dense path exactly."""
-    import jax.experimental.shard_map as _sm
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -231,11 +230,11 @@ def _moe_apply_ep(p, x, cfg: ArchConfig, ctx, ep: int):
         aux = jax.lax.pmean(aux, dp_axes) if dp_axes else aux
         return y, aux
 
-    fn = _sm.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, p["router"], p["we_g"], p["we_u"], p["we_d"])
     if m.n_shared:
         xf = x.reshape(B * S, d)

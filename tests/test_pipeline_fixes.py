@@ -100,6 +100,30 @@ def test_flush_deadline_bounds_wait(tiny_model, tmp_path):
     ld.close()
 
 
+def test_flush_deadline_spares_a_slow_advancing_consumer(tiny_model,
+                                                         tmp_path):
+    """The deadline bounds a wait with no progress, not the whole
+    drain: six items at 0.3 s each outlast a 0.5 s timeout, but each
+    lands inside it."""
+    store = CheckpointStore(str(tmp_path / "ck"))
+    ld = LowDiff(tiny_model, store, full_interval=100, batch_size=1)
+
+    def slow(step, cg):
+        time.sleep(0.3)
+        ld._processed += 1
+
+    ld._handle = slow
+    state = init_state(tiny_model, jax.random.PRNGKey(0), mode="lowdiff")
+    batch = make_batch(tiny_model.cfg, SEQ, BATCH)
+    for _ in range(6):
+        state, _ = ld.train_step(state, batch)
+    t0 = time.monotonic()
+    ld.flush(timeout=0.5)
+    assert ld._processed == 6
+    assert time.monotonic() - t0 > 0.5
+    ld.close()
+
+
 def test_lowdiff_plus_poisoned_persist_flush_raises(tiny_model, tmp_path):
     store = CheckpointStore(str(tmp_path / "ckp"))
     ldp = LowDiffPlus(tiny_model, store, persist_interval=1)

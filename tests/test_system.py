@@ -34,7 +34,8 @@ def test_launcher_end_to_end_with_failure(tmp_path):
         lr=1e-3, rho=0.05, strategy="lowdiff", full_interval=5,
         batch_size=2, ckpt_dir=str(tmp_path / "ck"), clean=True,
         fail_at=8, seed=0, log_every=0)
-    losses, times = T.run(args)
+    res = T.run(args)
+    losses = res.losses
     assert len(losses) == 12
     assert np.isfinite(losses).all()
 
