@@ -16,8 +16,11 @@ from repro.compression.sparse import compress_tree
 from repro.core.lowdiff import LowDiff, host_copy
 from repro.core.steps import init_state, make_train_step
 from repro.data.synthetic import make_batch
+from repro.obs.trace import TRACER
 
 BOUND = 0.035
+#: the checkpointing work LowDiff does on the training thread
+LOOP_SPANS = ("engine.queue_put", "snapshot.issue")
 
 
 def main(out):
@@ -39,10 +42,16 @@ def main(out):
                      batch_size=8, queue_size=64)
         st2 = dict(state)
         ld.train_step(st2, b)
-        t0 = ld.ckpt_time
-        for _ in range(4):
-            ld.train_step(st2, b)
-        lowdiff_cost = (ld.ckpt_time - t0) / 4
+        TRACER.clear()
+        TRACER.enable()
+        try:
+            for _ in range(4):
+                ld.train_step(st2, b)
+        finally:
+            TRACER.disable()
+        lowdiff_cost = sum(e[5] - e[4] for e in TRACER.events()
+                           if e[0] in LOOP_SPANS) / 4
+        TRACER.clear()
         ld.close()
 
         snap_cost = timeit(lambda: host_copy(state))      # CheckFreq/Gemini

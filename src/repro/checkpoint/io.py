@@ -95,10 +95,12 @@ class CopyMeter:
       time a consumer actually blocked for the bytes and ``span_s`` the
       issue-to-landed window, so ``d2h_overlap_ratio`` reports how much
       of the transfer hid behind compute (1.0 = fully overlapped).
-    * **H2D** — recovery-replay uploads back onto the device. These
-      were invisible before: recovery stacked payloads with jnp and the
-      implicit transfer never hit any counter, so benchmarks could not
-      report replay bandwidth honestly.
+    * **H2D** — recovery's uploads back onto the device: the
+      recovered full state (params and both moments, then the error
+      feedback; ``core.recovery.upload``) and the differentials'
+      payloads the replay stages. Recovery puts each on the device
+      explicitly, so no transfer is left implicit in a later dispatch
+      and uncounted: per resume this is the whole state plus the chain.
     """
 
     #: stats() keys, synced against the instrument set by
@@ -158,7 +160,7 @@ class CopyMeter:
         self._events.add(1)
 
     def add_h2d(self, nbytes: int) -> None:
-        """Replay-path host-to-device upload of checkpoint payloads."""
+        """Recovery's host-to-device upload of state or payloads."""
         self._h2d_bytes.add(int(nbytes))
         self._h2d_events.add(1)
 

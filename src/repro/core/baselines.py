@@ -19,7 +19,6 @@ stats) so the benchmark harness can swap them:
 """
 from __future__ import annotations
 
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
@@ -39,7 +38,6 @@ class _Base:
         self.model, self.store, self.lr = model, store, lr
         self.interval = interval
         self.step_fn = make_train_step(model, mode="dense", lr=lr)
-        self.ckpt_time = 0.0
         self._pool = ThreadPoolExecutor(max_workers=1)
         self._pending: List[Any] = []
 
@@ -60,8 +58,7 @@ class _Base:
         return self.store.load_full(entry), 0
 
     def stats(self):
-        return {"store": self.store.stats(),
-                "train_loop_ckpt_time": self.ckpt_time}
+        return {"store": self.store.stats()}
 
 
 class FullSync(_Base):
@@ -71,9 +68,7 @@ class FullSync(_Base):
         state, metrics, _ = self.step_fn(state, batch)
         step = int(state["step"])
         if step % self.interval == 0:
-            t0 = time.perf_counter()
             self.store.save_full(step, host_copy(state))   # blocking
-            self.ckpt_time += time.perf_counter() - t0
         return state, metrics
 
 
@@ -87,11 +82,9 @@ class CheckFreq(_Base):
         state, metrics, _ = self.step_fn(state, batch)
         step = int(state["step"])
         if step % self.interval == 0:
-            t0 = time.perf_counter()
             # snapshot() is synchronous w.r.t. the update (WAR hazard in
             # the paper's analysis); persist() is async.
             snap = host_copy(state)
-            self.ckpt_time += time.perf_counter() - t0
             self.flush()   # CheckFreq admits at most one in-flight persist
             self._pending.append(
                 self._pool.submit(self.store.save_full, step, snap))
@@ -113,10 +106,8 @@ class Gemini(_Base):
         state, metrics, _ = self.step_fn(state, batch)
         step = int(state["step"])
         if step % self.interval == 0:
-            t0 = time.perf_counter()
             self.memory_ckpt = host_copy(state)      # "peer CPU memory"
             self.memory_step = step
-            self.ckpt_time += time.perf_counter() - t0
         if step % self.persist_interval == 0:
             self._pending.append(self._pool.submit(
                 self.store.save_full, step, self.memory_ckpt))
@@ -160,7 +151,6 @@ class NaiveDC(_Base):
         old_state = state
         state, metrics, _ = self.step_fn(state, batch)
         step = int(state["step"])
-        t0 = time.perf_counter()
         if step % self.interval == 0:
             cd = self._diff_compress(state, old_state)
             jax.block_until_ready(jax.tree.leaves(cd)[0])   # Challenge 1 stall
@@ -170,7 +160,6 @@ class NaiveDC(_Base):
         if step % self.full_interval == 0:
             self._pending.append(self._pool.submit(
                 self.store.save_full, step, host_copy(state)))
-        self.ckpt_time += time.perf_counter() - t0
         return state, metrics
 
     def recover(self):

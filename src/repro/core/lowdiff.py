@@ -110,7 +110,6 @@ class LowDiff:
         self._tuning_history: "deque[Dict[str, Any]]" = deque(maxlen=256)
         self.tuning_resolves = 0
         self.tuning_applied = 0
-        self.ckpt_time = 0.0         # time spent inside the training loop
         self.full_saves = 0
 
     # ------------------------------------------------------------------
@@ -197,12 +196,13 @@ class LowDiff:
     def train_step(self, state, batch):
         if self._step_counter is None:
             self._step_counter = int(state["step"])   # one-time sync
-        state, metrics, cg = self.step_fn(state, batch)
-        t0 = time.perf_counter()
+        with trace_span("engine.dispatch", "engine"):
+            state, metrics, cg = self.step_fn(state, batch)
         self._step_counter += 1
         step = self._step_counter   # host-side: never forces the device
-        self._start_consumer()
-        blocked = self.queue.put(step, cg)    # zero-copy hand-off
+        with trace_span("engine.queue_put", "engine", step=step):
+            self._start_consumer()
+            blocked = self.queue.put(step, cg)    # zero-copy hand-off
         TIMELINE.charge("queue_backpressure", blocked)
         if step % self.full_interval == 0:
             # async snapshot: only enqueue the D2H transfers here — the
@@ -210,15 +210,15 @@ class LowDiff:
             # thread, overlapped with the next training step; sharded
             # mode additionally releases each shard's buffers as its
             # bytes land instead of pinning the whole model copy
-            if self.snapshot_shards > 0:
-                pending = self._arena.snapshot_sharded_async(
-                    state, shards=self.snapshot_shards)
-            else:
-                pending = self._arena.snapshot_async(state)
+            with trace_span("snapshot.issue", "snapshot", step=step):
+                if self.snapshot_shards > 0:
+                    pending = self._arena.snapshot_sharded_async(
+                        state, shards=self.snapshot_shards)
+                else:
+                    pending = self._arena.snapshot_async(state)
             self._pending.append(
                 self._persist_pool.submit(self._persist_full, step, pending))
             self.full_saves += 1
-        self.ckpt_time += time.perf_counter() - t0
         return state, metrics
 
     def _persist_full(self, step: int, pending):
@@ -277,6 +277,9 @@ class LowDiff:
         # at the first step gap (a write-back hole) rather than replay
         # across it into silently wrong state
         diffs = rec.contiguous_prefix(int(state["step"]), diffs)
+        # the replay cannot start before params and moments have landed
+        state["params"], state["opt"] = rec.upload(
+            (state["params"], state["opt"]), "recovery.h2d_state", wait=True)
         with trace_span("recovery.replay", "recovery", n=len(diffs),
                         mode=("device" if self.replay_device else
                               "parallel" if self.parallel_recovery
@@ -294,9 +297,14 @@ class LowDiff:
                                                 state["opt"],
                                                 diffs, lr=self.lr)
                 applied = len(diffs)
+        state["params"], state["opt"] = params, opt
+        if "ef" in state:
+            # issued behind the replay just dispatched; the next step
+            # is the first to read it
+            state["ef"] = rec.upload(state["ef"], "recovery.h2d_ef",
+                                     wait=False)
         TIMELINE.event("recovery", time.perf_counter() - t_rec,
                        step=self._step_counter)
-        state["params"], state["opt"] = params, opt
         if applied:
             # a payload that failed to decode cut the chain early; the
             # state is consistent as of the last *applied* differential
@@ -322,6 +330,5 @@ class LowDiff:
                            "resolves": self.tuning_resolves,
                            "history": list(self._tuning_history),
                            "params": dataclasses.asdict(self.tuner.p)},
-                "train_loop_ckpt_time": self.ckpt_time,
                 "full_saves": self.full_saves,
                 "timeline": TIMELINE.stats()}

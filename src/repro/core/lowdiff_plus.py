@@ -471,7 +471,6 @@ class LowDiffPlus:
         self._pending = []
         self._pending_lock = threading.Lock()
         self._processed = 0
-        self.ckpt_time = 0.0
         self.persists = 0
         self.patch_persists = 0
         self.leaves_deferred = 0
@@ -516,7 +515,6 @@ class LowDiffPlus:
             self.attach(state)
             self._step_counter = int(state["step"])
         state, metrics, grads = self.step_fn(state, batch)
-        t0 = time.perf_counter()
         self._step_counter += 1
         step = self._step_counter   # host-side: never forces the device
         self._start_consumer()
@@ -529,7 +527,6 @@ class LowDiffPlus:
                    for k, v in flat.items()}
         blocked = self.queue.put(step, futures)
         TIMELINE.charge("queue_backpressure", blocked)
-        self.ckpt_time += time.perf_counter() - t0
         return state, metrics
 
     def _handle(self, step: int, futures):
@@ -684,7 +681,6 @@ class LowDiffPlus:
 
     def stats(self):
         return {"queue": self.queue.stats(), "store": self.store.stats(),
-                "train_loop_ckpt_time": self.ckpt_time,
                 "persists": self.persists,
                 "persist_mode": self.persist_mode,
                 "dirty_granularity": self.dirty_granularity,

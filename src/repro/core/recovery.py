@@ -99,6 +99,24 @@ def contiguous_prefix(start: int, diffs: List[Tuple[int, Any]],
     return out
 
 
+def upload(tree, span: str, *, wait: bool):
+    """Put a recovered host tree on the device at the point recovery
+    chooses, not implicitly at whichever dispatch first reads it.
+    Counted in ``COPY_METER``'s H2D counters and traced as ``span``
+    with its ``bytes``. ``wait``: the span lasts until the bytes have
+    landed; otherwise it bounds only the issue of the transfers."""
+    from repro.checkpoint.io import COPY_METER
+    from repro.obs.trace import trace_span
+    with trace_span(span, "recovery") as sp:
+        nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(tree))
+        out = jax.device_put(tree)
+        if wait:
+            jax.block_until_ready(out)
+        COPY_METER.add_h2d(nbytes)
+        sp.set(bytes=nbytes)
+    return out
+
+
 def _is_compressed(x):
     from repro.compression.packed import PackedDiff
     from repro.compression.quant import QuantGrad

@@ -77,57 +77,62 @@ def make_train_step(model, *, mode: str = "lowdiff", rho: float = 0.01,
     (blockwise int8 — the paper's other §II-C compression family) or
     'packed' (fused top-k + int8 quantize + wire pack — the differential
     leaves the device already in frame layout). All produce reusable
-    differential checkpoints; EF applies to topk and packed."""
+    differential checkpoints; EF applies to topk and packed.
+
+    Each phase runs under a ``jax.named_scope`` (``fwd_bwd``,
+    ``compress`` with error feedback, ``decompress``, ``adam``): the
+    compiled ops carry it in their ``op_name`` metadata, so a profiler
+    trace can split the step's device time by phase."""
     cfg = model.cfg
     accum = cfg.grad_accum
 
     def step(state, batch):
         params = state["params"]
-        loss, metrics, grads = _grads(model, params, batch, accum)
-        extra = None
+        with jax.named_scope("fwd_bwd"):
+            loss, metrics, grads = _grads(model, params, batch, accum)
+        g_upd, ef, extra = grads, None, None
         if mode == "lowdiff":
+            with_ef = error_feedback and "ef" in state
             if compressor == "quant8":
                 from repro.compression.quant import (quant_compress,
                                                      quant_decompress)
-                cg = jax.tree.map(quant_compress, grads)
-                g_upd = jax.tree.map(
-                    quant_decompress, cg,
-                    is_leaf=lambda x: hasattr(x, "scale"))
-                ef = None
-                extra = cg
-                params2, opt2 = adam_update(params, g_upd, state["opt"],
-                                            lr=lr, b1=b1, b2=b2, eps=eps)
-                return ({"params": params2, "opt": opt2,
-                         "step": state["step"] + 1},
-                        dict(metrics, loss=loss), extra)
-            if compressor == "packed":
+                with jax.named_scope("compress"):
+                    cg = jax.tree.map(quant_compress, grads)
+                with jax.named_scope("decompress"):
+                    g_upd = jax.tree.map(
+                        quant_decompress, cg,
+                        is_leaf=lambda x: hasattr(x, "scale"))
+            elif compressor == "packed":
                 from repro.compression.packed import PackedDiff
                 from repro.kernels.ops import (packed_compress,
                                                packed_decompress)
                 is_pd = lambda x: isinstance(x, PackedDiff)  # noqa: E731
-                if error_feedback and "ef" in state:
-                    cg, ef = ef_compress_tree_with(
-                        grads, state["ef"],
-                        lambda g: packed_compress(g, rho),
-                        packed_decompress)
-                else:
-                    cg = jax.tree.map(lambda g: packed_compress(g, rho),
-                                      grads)
-                    ef = None
-                g_upd = jax.tree.map(packed_decompress, cg, is_leaf=is_pd)
-            elif error_feedback and "ef" in state:
-                cg, ef = ef_compress_tree(grads, state["ef"], rho)
-                g_upd = decompress_tree(cg)
+                with jax.named_scope("compress"):
+                    if with_ef:
+                        cg, ef = ef_compress_tree_with(
+                            grads, state["ef"],
+                            lambda g: packed_compress(g, rho),
+                            packed_decompress)
+                    else:
+                        cg = jax.tree.map(lambda g: packed_compress(g, rho),
+                                          grads)
+                with jax.named_scope("decompress"):
+                    g_upd = jax.tree.map(packed_decompress, cg,
+                                         is_leaf=is_pd)
             else:
-                cg, ef = compress_tree(grads, rho), None
-                g_upd = decompress_tree(cg)
+                with jax.named_scope("compress"):
+                    if with_ef:
+                        cg, ef = ef_compress_tree(grads, state["ef"], rho)
+                    else:
+                        cg = compress_tree(grads, rho)
+                with jax.named_scope("decompress"):
+                    g_upd = decompress_tree(cg)
             extra = cg
-        else:
-            g_upd, ef = grads, None
-            if mode == "lowdiff_plus":
-                extra = grads
-        params2, opt2 = adam_update(params, g_upd, state["opt"], lr=lr,
-                                    b1=b1, b2=b2, eps=eps)
+        elif mode == "lowdiff_plus":
+            extra = grads
+        with jax.named_scope("adam"):
+            params2, opt2 = adam_update(params, g_upd, state["opt"], lr=lr,
+                                        b1=b1, b2=b2, eps=eps)
         new_state = {"params": params2, "opt": opt2,
                      "step": state["step"] + 1}
         if ef is not None:

@@ -3,13 +3,22 @@
 The checkpoint pipeline spreads one logical step across four threads
 (main step loop, persist worker, maintenance worker, peer-replication
 worker); a flat log can't show why a step stalled. This tracer records
-``(name, category, tid, t_start, t_end, attrs)`` spans into a
-``deque(maxlen=...)`` ring (appends are GIL-atomic; the bound makes a
-week-long run safe by construction) and exports the Chrome
+``(name, category, tid, thread_name, t_start, t_end, attrs, parent)``
+spans into a ``deque(maxlen=...)`` ring (appends are GIL-atomic; the
+bound makes a week-long run safe by construction) and exports the Chrome
 ``trace_event`` JSON that chrome://tracing and Perfetto render as a
 per-thread flame chart of the full lifecycle: step compute →
 dirty-snapshot D2H → compress → persist-queue wait → backend write
 (per tier) → peer fanout → fold/GC slices → replay H2D.
+
+``parent`` is the name of the span that was open on the same thread
+when this one began (``None`` at the top): the span that caused it.
+
+While enabled, each span also enters a ``jax.profiler.TraceAnnotation``
+of its name, so any ``jax.profiler`` trace taken meanwhile holds the
+pipeline's spans on the profiler's own clock, on the thread that ran
+them, next to the device ops they launched or waited for. JAX is
+imported on the first ``enable``.
 
 Cost discipline: tracing is **disabled by default** and the disabled
 path is one attribute load + truthiness test returning a module-level
@@ -33,7 +42,7 @@ class _Span:
     """An open span; ``__exit__`` stamps the end time and commits the
     event tuple to the ring."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "t0")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "t0", "parent", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -42,8 +51,16 @@ class _Span:
         self.cat = cat
         self.attrs = attrs
         self.t0 = 0.0
+        self.parent: Optional[str] = None
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        t = self._tracer
+        stack = t._open_spans()
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
+        self._ann = t._annotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -56,10 +73,12 @@ class _Span:
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         t = self._tracer
+        t._open_spans().pop()
         th = threading.current_thread()
         t._events.append((self.name, self.cat, th.ident, th.name,
-                          self.t0, t1, self.attrs))
+                          self.t0, t1, self.attrs, self.parent))
         t.events_total += 1
 
 
@@ -89,14 +108,21 @@ class SpanTracer:
     DEFAULT_BUFFER = 65536
 
     def __init__(self, buffer: int = DEFAULT_BUFFER, enabled: bool = False):
-        self.enabled = enabled
+        self.enabled = False
         self.events_total = 0
         self._events: deque = deque(maxlen=buffer)
+        self._local = threading.local()
+        self._annotation = None    #: jax.profiler.TraceAnnotation
+        if enabled:
+            self.enable()
 
     # -- control ------------------------------------------------------
     def enable(self, buffer: Optional[int] = None) -> None:
         if buffer is not None and buffer != self._events.maxlen:
             self._events = deque(self._events, maxlen=max(1, buffer))
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:
@@ -107,6 +133,13 @@ class SpanTracer:
         self.events_total = 0
 
     # -- recording ----------------------------------------------------
+    def _open_spans(self) -> List["_Span"]:
+        """This thread's stack of open spans (innermost last)."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def span(self, name: str, cat: str = "pipeline", **attrs):
         if not self.enabled:
             return _NOOP
@@ -137,7 +170,7 @@ class SpanTracer:
         pid = os.getpid()
         events: List[Dict[str, Any]] = []
         threads: Dict[int, str] = {}
-        for (name, cat, tid, tname, t0, t1, attrs) in list(self._events):
+        for (name, cat, tid, tname, t0, t1, attrs, _) in list(self._events):
             threads.setdefault(tid, tname)
             ev: Dict[str, Any] = {
                 "name": name, "cat": cat, "ph": "X",

@@ -1,5 +1,6 @@
 """End-to-end tests of the LowDiff / LowDiff+ core (the paper's system)."""
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +149,72 @@ def test_lowdiff_recovery_parallel_matches_serial(trained_lowdiff):
                        atol=1e-6, rtol=1e-5)
     assert_trees_close(rec_state["opt"].mu, live["opt"].mu,
                        atol=1e-6, rtol=1e-5)
+
+
+def test_lowdiff_recover_uploads_state_explicitly(trained_lowdiff):
+    """Recovery puts the full's params, moments and error feedback on
+    the device itself, meters those bytes with the replay's payloads,
+    and traces each upload where it happens."""
+    from repro.checkpoint.io import COPY_METER
+    from repro.obs.trace import TRACER
+    _, store, ld, live = trained_lowdiff
+    ld.replay_device = True
+    full = store.load_full(store.manifest["fulls"][-1])
+    nbytes = lambda t: sum(np.asarray(x).nbytes  # noqa: E731
+                           for x in jax.tree.leaves(t))
+    state_bytes = nbytes((full["params"], full["opt"]))
+    ef_bytes = nbytes(full["ef"])
+    del full
+    COPY_METER.reset()
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        rec_state, n = ld.recover()
+    finally:
+        TRACER.disable()
+    spans = {e[0]: e for e in TRACER.events()}
+    TRACER.clear()
+    assert n == 2
+    h2d_state, h2d_ef = spans["recovery.h2d_state"], spans["recovery.h2d_ef"]
+    replay, staged = spans["recovery.replay"], spans["replay.h2d"]
+    assert h2d_state[6] == {"bytes": state_bytes}
+    assert h2d_ef[6] == {"bytes": ef_bytes}
+    assert staged[7] == "recovery.replay"
+    # state lands before the replay; the EF is issued behind it
+    assert h2d_state[5] <= replay[4] <= replay[5] <= h2d_ef[4]
+    assert COPY_METER.h2d_bytes == (state_bytes + ef_bytes
+                                    + staged[6]["bytes"])
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(
+        (rec_state["params"], rec_state["opt"], rec_state["ef"])))
+    assert_trees_close(rec_state["params"], live["params"],
+                       atol=1e-8, rtol=1e-4)
+    COPY_METER.reset()
+
+
+def test_lowdiff_train_step_spans(tmp_path):
+    """The training thread's share of checkpointing is traced: one
+    dispatch and one queue hand-off a step, a snapshot issue at each
+    full save."""
+    from repro.obs.trace import TRACER
+    model = tiny_model()
+    ld = LowDiff(model, CheckpointStore(str(tmp_path / "ckpt")), rho=0.05,
+                 full_interval=3, batch_size=2)
+    state = init_state(model, jax.random.PRNGKey(0), mode="lowdiff")
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        for t in range(4):
+            state, _ = ld.train_step(
+                state, make_batch(model.cfg, SEQ, BATCH, step=t))
+    finally:
+        TRACER.disable()
+        ld.close()
+    main = threading.get_ident()
+    names = [e[0] for e in TRACER.events() if e[2] == main
+             and e[0].startswith(("engine.", "snapshot.issue"))]
+    TRACER.clear()
+    assert names == ["engine.dispatch", "engine.queue_put"] * 3 + [
+        "snapshot.issue", "engine.dispatch", "engine.queue_put"]
 
 
 def test_lowdiff_diffs_much_smaller_than_full(trained_lowdiff):

@@ -190,7 +190,7 @@ class TestTracer:
     def test_attrs_set_mid_span(self, tracer):
         with tracer.span("persist.batch", "persist", n=4) as sp:
             sp.set(bytes=123)
-        (_, _, _, _, _, _, attrs) = tracer.events()[0]
+        (_, _, _, _, _, _, attrs, _) = tracer.events()[0]
         assert attrs == {"n": 4, "bytes": 123}
 
     def test_traced_decorator(self, global_tracer):
@@ -232,6 +232,44 @@ class TestTracer:
         with pytest.raises(ValueError):  # complete event missing ts/dur
             load_chrome_trace(str(bad))
 
+    def test_ring_tuple_fields(self, tracer):
+        """The first seven fields keep their meaning; the eighth is the
+        parent span's name."""
+        with tracer.span("outer", "engine", k=1):
+            with tracer.span("inner", "recovery"):
+                pass
+        inner, outer = tracer.events()
+        th = threading.current_thread()
+        assert inner[:4] == ("inner", "recovery", th.ident, th.name)
+        assert outer[:4] == ("outer", "engine", th.ident, th.name)
+        assert outer[4] <= inner[4] <= inner[5] <= outer[5]
+        assert (inner[6], outer[6]) == (None, {"k": 1})
+        assert (inner[7], outer[7]) == ("outer", None)
+        xs = [e for e in tracer.to_chrome()["traceEvents"] if e["ph"] == "X"]
+        assert [(e["name"], e["cat"], e["tid"]) for e in xs] == [
+            ("inner", "recovery", th.ident), ("outer", "engine", th.ident)]
+        assert xs[1]["args"] == {"k": 1} and "args" not in xs[0]
+        assert xs[0]["ts"] == round(inner[4] * 1e6, 3)
+
+    def test_parent_is_per_thread(self, tracer):
+        """A span's parent is the span open on its own thread: a worker
+        started inside a main-thread span has none."""
+        def work():
+            with tracer.span("w.outer"):
+                with tracer.span("w.inner"):
+                    pass
+
+        with tracer.span("main"):
+            th = threading.Thread(target=work, name="worker")
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+            with tracer.span("main.child"):
+                pass
+        parents = {e[0]: e[7] for e in tracer.events()}
+        assert parents == {"w.inner": "w.outer", "w.outer": None,
+                           "main.child": "main", "main": None}
+
     def test_enable_resizes_ring(self):
         t = SpanTracer(buffer=8, enabled=True)
         for i in range(8):
@@ -240,6 +278,74 @@ class TestTracer:
         t.enable(4)
         assert len(t) == 4  # keeps the newest 4
         assert t.events()[-1][0] == "s7"
+
+
+# ---------------------------------------------------------------------
+# spans in the jax.profiler trace
+# ---------------------------------------------------------------------
+def _profiled(tmp_path, body):
+    """Run ``body`` under ``jax.profiler`` and return the host events
+    as {name: [(line index, start_ns, end_ns)]}."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (i, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+class TestProfilerBridge:
+    def test_spans_of_two_threads_nest_in_the_profiler_trace(
+            self, global_tracer, tmp_path):
+        def work():
+            with trace_span("bridge.worker", "persist"):
+                with trace_span("bridge.worker_inner", "persist"):
+                    time.sleep(0.002)
+
+        def body():
+            with trace_span("bridge.main", "recovery"):
+                with trace_span("bridge.main_inner", "recovery"):
+                    th = threading.Thread(target=work, name="bridge-w")
+                    th.start()
+                    th.join(timeout=10)
+                    assert not th.is_alive()
+
+        evs = _profiled(tmp_path, body)
+        (m,), (mi,) = evs["bridge.main"], evs["bridge.main_inner"]
+        (w,), (wi,) = evs["bridge.worker"], evs["bridge.worker_inner"]
+        assert m[0] == mi[0] and w[0] == wi[0] and m[0] != w[0]
+        assert m[1] <= mi[1] <= mi[2] <= m[2]
+        assert w[1] <= wi[1] <= wi[2] <= w[2]
+        # the ring records the same nesting, per thread
+        parents = {e[0]: e[7] for e in global_tracer.events()}
+        assert parents["bridge.main_inner"] == "bridge.main"
+        assert parents["bridge.worker_inner"] == "bridge.worker"
+        assert parents["bridge.worker"] is None
+
+    def test_disabled_tracer_puts_nothing_in_the_profiler_trace(
+            self, tmp_path):
+        assert not TRACER.enabled
+
+        def body():
+            with trace_span("bridge.disabled", "recovery"):
+                time.sleep(0.001)
+
+        evs = _profiled(tmp_path, body)
+        assert "bridge.disabled" not in evs
+        assert len(TRACER) == 0
 
 
 # ---------------------------------------------------------------------
