@@ -23,9 +23,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compression.error_feedback import (ef_compress_tree,
-                                              ef_compress_tree_with, ef_init)
-from repro.compression.sparse import compress_tree, decompress_tree
+from repro.compression.error_feedback import ef_compress_tree_with, ef_init
 from repro.optim.adam import adam_init, adam_update
 
 
@@ -69,6 +67,20 @@ def _grads(model, params, batch, accum: int):
                   "tokens": jnp.float32(0)}, grads
 
 
+def _topk_tree(grads, ef, rho: float):
+    """(wire, dense picks, new residual) of every leaf: one fused pass
+    of ``kernels.ops.ef_topk_compress`` per leaf. Without error feedback
+    (``ef`` None) the residual tree is None."""
+    from repro.kernels.ops import ef_topk_compress
+    g_flat, treedef = jax.tree.flatten(grads)
+    e_flat = ([None] * len(g_flat) if ef is None
+              else treedef.flatten_up_to(ef))
+    outs = [ef_topk_compress(g, e, rho) for g, e in zip(g_flat, e_flat)]
+    cg, dense, res = ([o[i] for o in outs] for i in range(3))
+    return (jax.tree.unflatten(treedef, cg), jax.tree.unflatten(treedef, dense),
+            None if ef is None else jax.tree.unflatten(treedef, res))
+
+
 def make_train_step(model, *, mode: str = "lowdiff", rho: float = 0.01,
                     lr: float = 1e-3, b1: float = 0.9, b2: float = 0.999,
                     eps: float = 1e-8, error_feedback: bool = True,
@@ -82,7 +94,9 @@ def make_train_step(model, *, mode: str = "lowdiff", rho: float = 0.01,
     Each phase runs under a ``jax.named_scope`` (``fwd_bwd``,
     ``compress`` with error feedback, ``decompress``, ``adam``): the
     compiled ops carry it in their ``op_name`` metadata, so a profiler
-    trace can split the step's device time by phase."""
+    trace can split the step's device time by phase. The ``topk``
+    compress is one fused kernel pass per leaf that also emits the
+    decompressed gradient, so that step has no ``decompress`` ops."""
     cfg = model.cfg
     accum = cfg.grad_accum
 
@@ -121,12 +135,8 @@ def make_train_step(model, *, mode: str = "lowdiff", rho: float = 0.01,
                                          is_leaf=is_pd)
             else:
                 with jax.named_scope("compress"):
-                    if with_ef:
-                        cg, ef = ef_compress_tree(grads, state["ef"], rho)
-                    else:
-                        cg = compress_tree(grads, rho)
-                with jax.named_scope("decompress"):
-                    g_upd = decompress_tree(cg)
+                    cg, g_upd, ef = _topk_tree(
+                        grads, state["ef"] if with_ef else None, rho)
             extra = cg
         elif mode == "lowdiff_plus":
             extra = grads

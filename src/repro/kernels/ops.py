@@ -10,6 +10,7 @@ larger jitted graphs).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ import numpy as np
 
 from repro.compression.packed import PackedDiff
 from repro.compression.quant import QuantGrad
+from repro.compression import sparse as _csp
 from repro.compression.sparse import BLOCK, SparseGrad, _pad_len, k_for
 from repro.kernels import fused_adam as _fa
 from repro.kernels import pack as _pk
@@ -24,6 +26,9 @@ from repro.kernels import quant8 as _q8
 from repro.kernels import ref as _ref
 from repro.kernels import replay as _rp
 from repro.kernels import topk as _tk
+
+#: the most blocks the fused top-k builds from one group of a leaf's rows
+MAX_GROUP = 8
 
 
 def _interpret() -> bool:
@@ -50,16 +55,66 @@ def _to_blocks(x: jax.Array, block: int):
     return xb, xb.shape[0] - rpad
 
 
+def _fused_view(shape, block: int):
+    """(rows, width, pad): the 2-D view ``topk.ef_topk`` reads a leaf
+    through, adapted to the leaf's shape. The leaf's own rows when its
+    last dim is a width the kernel takes (no relayout); else one
+    relayout to the widest such width that divides its size. A leaf of
+    one tile or less, or whose size is no multiple of 128, is cut flat
+    into whole blocks (padded): its copy costs next to nothing."""
+    n = math.prod(shape)
+
+    def takes(w):
+        g, c = _tk.group_of(w, block)
+        aligned = w % 128 == 0 and block % 128 == 0
+        return (c <= MAX_GROUP and (aligned or w == block) and n % w == 0
+                and n // w >= 8 * g)
+
+    if n > _tk.TILE_BLOCKS * block:
+        for w in [shape[-1] if shape else 1] + [128 * m for m in range(
+                MAX_GROUP * block // 128, 0, -1)]:
+            if takes(w):
+                return n // w, w, 0
+    rows = -(-n // block)
+    rows += -rows % 8
+    return rows, block, rows * block - n
+
+
+@functools.partial(jax.jit, static_argnames=("rho", "block"))
+def ef_topk_compress(g: jax.Array, e, rho: float, *, block: int = BLOCK):
+    """Blockwise top-k of ``g + e`` with error feedback ``e`` (or of
+    ``g`` alone where ``e`` is None), in one fused pass over the leaf.
+
+    Returns (SparseGrad, dense, residual): the wire, exactly
+    ``compression.sparse.topk_compress(g + e, rho)``; the picks in
+    place and zeros elsewhere, which is ``topk_decompress`` of the wire;
+    and the new residual ``g + e`` with the picks zeroed, which is
+    ``g + e - dense`` (None without ``e``)."""
+    k = k_for(rho, block)
+    shape, n = g.shape, math.prod(g.shape)
+    rows, width, pad = _fused_view(shape, block)
+
+    def view(x):
+        return jnp.pad(x.reshape(-1), (0, pad)).reshape(rows, width) \
+            if pad else x.reshape(rows, width)
+
+    def unview(x):
+        return x.reshape(-1)[:n].reshape(shape) if pad else x.reshape(shape)
+
+    vals, idxs, dense, res = _tk.ef_topk(
+        view(g), None if e is None else view(e), k, block=block,
+        interpret=_interpret())
+    nb = -(-n // block)
+    return (SparseGrad(vals[:nb], idxs[:nb], shape, block), unview(dense),
+            None if res is None else unview(res))
+
+
 @functools.partial(jax.jit, static_argnames=("rho", "block", "use_pallas"))
 def topk_compress(x: jax.Array, rho: float, *, block: int = BLOCK,
                   use_pallas: bool = True) -> SparseGrad:
-    xb, nb = _to_blocks(x, block)
-    k = k_for(rho, block)
     if use_pallas:
-        vals, idx = _tk.topk_select(xb, k, interpret=_interpret())
-    else:
-        vals, idx = _ref.topk_select_ref(xb, k)
-    return SparseGrad(vals[:nb], idx[:nb], x.shape, block)
+        return ef_topk_compress(x, None, rho, block=block)[0]
+    return _csp.topk_compress(x, rho, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))

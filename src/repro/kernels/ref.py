@@ -5,14 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def topk_select_ref(xb: jax.Array, k: int):
-    """xb: (nb, block) -> (values, indices) — magnitude top-k per block."""
-    mag = jnp.abs(xb.astype(jnp.float32))
-    _, idx = jax.lax.top_k(mag, k)
-    vals = jnp.take_along_axis(xb, idx, axis=1)
-    return vals, idx.astype(jnp.int32)
-
-
 def topk_scatter_ref(vals: jax.Array, idxs: jax.Array, block: int):
     nb, k = vals.shape
     out = jnp.zeros((nb, block), vals.dtype)

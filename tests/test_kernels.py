@@ -51,6 +51,52 @@ def test_topk_kernel_rho_sweep(rho):
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), atol=1e-6)
 
 
+def _ef_case(name):
+    """(gradient, residual) of one leaf for the fused top-k parity test."""
+    rng = np.random.default_rng(7)
+
+    def normal(shape, scale=1.0):
+        return (rng.normal(size=shape) * scale).astype(np.float32)
+
+    def ties(shape, scale):      # few magnitudes, +x and -x alike
+        return (rng.integers(-3, 4, size=shape) * scale).astype(np.float32)
+
+    if name == "ties":
+        return ties((16, 1024), 0.25), np.zeros((16, 1024), np.float32)
+    if name == "zero_blocks":
+        g, e = normal((16, 1024)), normal((16, 1024), 0.1)
+        g[3:9], e[3:9] = 0.0, 0.0
+        return g, e
+    if name == "grouped_ties":   # 4 rows of 1280 hold 5 blocks; ragged tile
+        return ties((261, 1280), 0.5), ties((261, 1280), 0.25)
+    shape = {"norm": (1280,), "ragged": (50257, 8),
+             "mlp3d": (2, 60, 5120)}[name]
+    return normal(shape), normal(shape, 0.1)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("rho", [0.001, 0.01, 0.1])
+@pytest.mark.parametrize("case", ["ties", "zero_blocks", "grouped_ties",
+                                  "norm", "ragged", "mlp3d"])
+def test_ef_topk_kernel_matches_oracle(case, rho):
+    """The fused error-feedback top-k equals, bit for bit,
+    ``ef_compress_tree`` + ``topk_decompress``: values, indices, the new
+    residual and the dense picks, on every layout the kernel takes."""
+    from repro.compression.error_feedback import ef_compress_tree
+    g, e = (jnp.asarray(a) for a in _ef_case(case))
+    sg, dense, res = kops.ef_topk_compress(g, e, rho)
+    (cg,), (ef,) = ef_compress_tree([g], [e], rho)
+    assert sg.shape == cg.shape and sg.block == cg.block
+    for a, b in [(sg.values, cg.values), (sg.indices, cg.indices),
+                 (res, ef), (dense, csp.topk_decompress(cg))]:
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_quant_kernel_matches_ref(shape, dtype):

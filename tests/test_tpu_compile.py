@@ -30,6 +30,12 @@ LEAVES = {"mlp": 1280 * 5120, "embed": 50257 * 1280, "norm": 1280}
 SPANS = [(1280, 5120), (50264, 1280), (1280, 50258)]
 WIDE = (8, 1280 * 5120)
 
+#: gpt2-l leaves as the fused top-k reads them: the stacked SwiGLU
+#: matrix in its own rows, the embedding in groups of 4 rows of 1280
+#: (5 blocks), the head after its one relayout, and a norm cut flat
+EF_LEAVES = {"mlp": (8, 1280, 5120), "embed": (50257, 1280),
+             "head": (1280, 50257), "norm": (1280,)}
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -68,7 +74,6 @@ def _block_cases(name, nb, S):
     idx, q, scale = S((nb, K), i32), S((nb, K), i8), S((nb, 1), f32)
     state, hyper = [dense] * 3, S((1, 8), f32)
     return {
-        "topk_select": (lambda x: topk.topk_select(x, K), [dense]),
         "topk_scatter": (lambda v, i: topk.topk_scatter(v, i, BLOCK),
                          [wire, idx]),
         "pack_select": (lambda x: pack.pack_select(x, K), [dense]),
@@ -111,7 +116,7 @@ def _assert_compiles(fn, args):
 
 
 @pytest.mark.parametrize("name", [
-    "topk_select", "topk_scatter", "pack_select", "pack_scatter",
+    "topk_scatter", "pack_select", "pack_scatter",
     "topk_apply", "packed_apply", "quant_apply", "quantize", "dequantize",
     "adam_tile_update"])
 def test_block_kernel_compiles_for_v5e(name, one_chip):
@@ -132,3 +137,14 @@ def test_span_kernel_compiles_for_v5e(name, bits, one_chip):
     shapes = SPANS if name == "span_pack" else SPANS + [WIDE]
     for rows, cols in shapes:
         _assert_compiles(*_span_cases(name, bits, rows, cols, S))
+
+
+@pytest.mark.parametrize("leaf,with_ef", [
+    ("mlp", True), ("embed", True), ("head", True), ("norm", True),
+    ("embed", False)])
+def test_ef_topk_compiles_for_v5e(leaf, with_ef, one_chip):
+    from repro.kernels.ops import _fused_view
+    rows, width, _ = _fused_view(EF_LEAVES[leaf], BLOCK)
+    x = jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip)
+    _assert_compiles(lambda g, e=None: topk.ef_topk(g, e, K, block=BLOCK),
+                     [x, x] if with_ef else [x])
